@@ -15,6 +15,10 @@ the scheduler:
   drains).  Cells are sorted heavy-first (budget x trace length) and
   submitted in small chunks, so free workers steal queued chunks and a
   heavy cell can never straggle the tail of the pool.
+* **no chip in the workers**: they run only the numpy planner and
+  simulator.  ``repro.core.study`` imports no JAX, so no worker needs the
+  chip or can contend for it with a parent that holds it.  Keep it so
+  (``tests/test_sweep.py`` checks the import).
 * **determinism**: every cell derives its streams from
   ``np.random.SeedSequence`` spawn keys rooted at the grid seed, so the
   aggregate is byte-identical for any worker count; ``--smoke`` proves it

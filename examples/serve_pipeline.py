@@ -15,11 +15,13 @@ import numpy as np
 from repro.core import adapter as AD
 from repro.core import optimizer as OPT
 from repro.core import trace as TR
-from repro.launch.serve import build_pipeline
+from repro.launch.serve import build_pipeline, pipeline_families
 
 
 def main() -> None:
-    pipe, engine = build_pipeline("vlm-classify", gen_tokens=2,
+    pipe, engine = build_pipeline("vlm-classify",
+                                  pipeline_families("vlm-classify"),
+                                  gen_tokens=2,
                                   profile_batches=(1, 2), th=0.5)
     print(f"profiled pipeline SLA_P = {pipe.sla:.2f}s")
     for st in pipe.stages:

@@ -90,7 +90,8 @@ def measure_latency(fn: Callable[[int], None], batches=BATCH_CHOICES,
 
 def profile_stage_server(server, batches=(1, 2, 4, 8), prompt_len: int = 16,
                          repeats: int = 2) -> List[Profile]:
-    """Profile every variant of a real serving StageServer (JAX CPU backend)."""
+    """Profile every variant of a real serving StageServer, on whichever
+    device JAX runs it (wall clock around ``process``, which blocks)."""
     import numpy as _np
     profs = []
     for vname, (cfg, acc) in server.variants.items():

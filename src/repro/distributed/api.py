@@ -29,23 +29,11 @@ def get_axis_rules() -> Optional[dict]:
 
 def _active_mesh_axes() -> Optional[Tuple[Tuple[str, ...], Tuple[int, ...]]]:
     """(axis_names, axis_sizes) of the ambient mesh, or None when no mesh
-    is active.  Newer jax exposes ``jax.sharding.get_abstract_mesh``; older
-    releases track the ``with mesh:`` context in thread resources."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        mesh = get()
-        if mesh is None or mesh.empty:
-            return None
-        return tuple(mesh.axis_names), tuple(mesh.axis_sizes)
-    try:
-        from jax._src import mesh as _mesh_lib
-        mesh = _mesh_lib.thread_resources.env.physical_mesh
-    except Exception:
+    is active."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return None
-    if mesh is None or mesh.empty:
-        return None
-    return tuple(mesh.axis_names), tuple(mesh.shape[n]
-                                         for n in mesh.axis_names)
+    return tuple(mesh.axis_names), tuple(mesh.axis_sizes)
 
 
 def resolve(spec_names: Tuple[Optional[str], ...]) -> P:

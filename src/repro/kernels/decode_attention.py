@@ -5,6 +5,9 @@ once per token), so the kernel is organized to read each cache block exactly
 once: grid (B, KV_heads, num_cache_blocks), sequential over cache blocks with
 the per-(batch, kv-head) group of GQA query heads (H/KV of them) resident in
 VMEM.  A `lengths` operand masks ring-buffer slots past the valid length.
+The wrapper moves kv heads ahead of the cache's sequence axis, so each cache
+block is (1, 1, block_k, hd): a sublane-aligned tile over the whole head dim,
+the tiling the TPU compiler requires.
 """
 from __future__ import annotations
 
@@ -14,8 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -30,9 +31,9 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0, :, :]                                   # (group, hd)
-    k = k_ref[0, :, 0, :]                                   # (bk, hd)
-    v = v_ref[0, :, 0, :]
+    q = q_ref[0, 0]                                         # (group, hd)
+    k = k_ref[0, 0]                                         # (bk, hd)
+    v = v_ref[0, 0]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
 
@@ -53,7 +54,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
     def _flush():
         l = l_ref[...]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0, :, :] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
@@ -70,6 +71,9 @@ def decode_attention(q, k_cache, v_cache, lengths, *, block_k: int = 128,
     nk = L // block_k
     scale = 1.0 / (hd ** 0.5)
     qg = q.reshape(b, kv, group, hd)
+    # kv heads ahead of the cache's sequence axis: (B, KV, L, hd)
+    kt = k_cache.transpose(0, 2, 1, 3)
+    vt = v_cache.transpose(0, 2, 1, 3)
 
     kernel = functools.partial(_decode_kernel, bk=block_k, nk=nk, scale=scale)
 
@@ -79,8 +83,8 @@ def decode_attention(q, k_cache, v_cache, lengths, *, block_k: int = 128,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, group, hd), lambda b, g, ki: (b, g, 0, 0)),
-            pl.BlockSpec((1, block_k, 1, hd), lambda b, g, ki: (b, ki, g, 0)),
-            pl.BlockSpec((1, block_k, 1, hd), lambda b, g, ki: (b, ki, g, 0)),
+            pl.BlockSpec((1, 1, block_k, hd), lambda b, g, ki: (b, g, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, hd), lambda b, g, ki: (b, g, ki, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, group, hd), lambda b, g, ki: (b, g, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, kv, group, hd), q.dtype),
@@ -89,8 +93,8 @@ def decode_attention(q, k_cache, v_cache, lengths, *, block_k: int = 128,
             pltpu.VMEM((group, 1), jnp.float32),
             pltpu.VMEM((group, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), qg, k_cache, v_cache)
+    )(lengths.astype(jnp.int32), qg, kt, vt)
     return out.reshape(b, h, hd)

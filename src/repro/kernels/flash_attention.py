@@ -4,8 +4,11 @@ Block-tiled online-softmax attention.  Grid is (B, H, num_q_blocks,
 num_kv_blocks) with the KV axis sequential ("arbitrary") so the f32
 accumulator/row-max/row-sum scratch in VMEM carries across KV blocks.
 GQA is handled in the index map (kv head = h // (H // KV)) — the kernel never
-materializes repeated K/V.  Block sizes default to MXU-aligned 128s; the
-per-step VMEM working set is
+materializes repeated K/V.  The wrapper moves heads ahead of the sequence
+axis, so every block is (1, 1, block, hd): its last two dimensions are a
+sublane-aligned sequence tile and the whole head dim, the tiling the TPU
+compiler requires.  Block sizes default to MXU-aligned 128s; the per-step
+VMEM working set is
   bq*hd (q) + 2*bk*hd (k,v) + bq*bk (scores) + bq*hd (acc)  floats.
 """
 from __future__ import annotations
@@ -17,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -34,9 +35,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, :, 0, :]                                   # (bq, hd)
-    k = k_ref[0, :, 0, :]                                   # (bk, hd)
-    v = v_ref[0, :, 0, :]
+    q = q_ref[0, 0]                                         # (bq, hd)
+    k = k_ref[0, 0]                                         # (bk, hd)
+    v = v_ref[0, 0]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
 
@@ -62,7 +63,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     def _flush():
         l = l_ref[...]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -86,22 +87,25 @@ def flash_attention(q, k, v, *, causal: bool = True,
         _flash_kernel, bq=block_q, bk=block_k, nk=nk, scale=scale,
         causal=causal, window=window)
 
-    return pl.pallas_call(
+    # heads ahead of the sequence: (B, H, S, hd)
+    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    out = pl.pallas_call(
         kernel,
         grid=(b, h, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, hd), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, block_k, 1, hd), lambda b, h, qi, ki: (b, ki, h // group, 0)),
-            pl.BlockSpec((1, block_k, 1, hd), lambda b, h, qi, ki: (b, ki, h // group, 0)),
+            pl.BlockSpec((1, 1, block_q, hd), lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, block_k, hd), lambda b, h, qi, ki: (b, h // group, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, hd), lambda b, h, qi, ki: (b, h // group, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, hd), lambda b, h, qi, ki: (b, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, hd), lambda b, h, qi, ki: (b, h, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, hd), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q, k, v)
+    )(qt, kt, vt)
+    return out.transpose(0, 2, 1, 3)

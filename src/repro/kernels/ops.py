@@ -1,35 +1,22 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode; on a real TPU
-set ``REPRO_PALLAS_INTERPRET=0`` (or rely on the default backend detection)
-to lower them to Mosaic.
+The kernels lower to Mosaic for the TPU.  ``interpret=True`` runs them in
+the Pallas interpreter instead, which is how the tests check them on a host
+without a chip; nothing switches to it on its own.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
-
-import jax
-import jax.numpy as jnp
 
 from repro.kernels import decode_attention as _dec
 from repro.kernels import flash_attention as _fa
 from repro.kernels import ssd_scan as _ssd
 
 
-def _interpret_default() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
-
-
 def flash_attention(q, k, v, q_pos=None, k_pos=None, *, causal: bool = True,
                     window: Optional[int] = None, block_q: int = 128,
-                    block_k: int = 128, interpret: Optional[bool] = None):
+                    block_k: int = 128, interpret: bool = False):
     """Signature-compatible with repro.models.layers.attention."""
-    if interpret is None:
-        interpret = _interpret_default()
     bq = min(block_q, q.shape[1])
     bk = min(block_k, k.shape[1])
     return _fa.flash_attention(q, k, v, causal=causal, window=window,
@@ -37,18 +24,14 @@ def flash_attention(q, k, v, q_pos=None, k_pos=None, *, causal: bool = True,
 
 
 def decode_attention(q, k_cache, v_cache, lengths, *, block_k: int = 128,
-                     interpret: Optional[bool] = None):
-    if interpret is None:
-        interpret = _interpret_default()
+                     interpret: bool = False):
     bk = min(block_k, k_cache.shape[1])
     return _dec.decode_attention(q, k_cache, v_cache, lengths,
                                  block_k=bk, interpret=interpret)
 
 
 def ssd_scan(x, dt, a_neg, b_mat, c_mat, *, chunk: int = 256,
-             init_state=None, interpret: Optional[bool] = None):
-    if interpret is None:
-        interpret = _interpret_default()
+             init_state=None, interpret: bool = False):
     chunk = min(chunk, x.shape[1])
     return _ssd.ssd_scan(x, dt, a_neg, b_mat, c_mat, chunk=chunk,
                          init_state=init_state, interpret=interpret)

@@ -282,13 +282,8 @@ def make_custom_mesh(spec: str):
     """'32x8' -> (data=32, model=8) mesh over the first 256 host devices."""
     d, m = (int(x) for x in spec.split("x"))
     devs = np.array(jax.devices()[:d * m]).reshape(d, m)
-    from jax.sharding import Mesh
-
-    from repro.launch.mesh import mesh_axis_types
-    at = mesh_axis_types(2)
-    if at is None:
-        return Mesh(devs, ("data", "model"))
-    return Mesh(devs, ("data", "model"), axis_types=at)
+    from jax.sharding import AxisType, Mesh
+    return Mesh(devs, ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 
 
 def run_case(arch: str, shape_name: str, *, multi_pod: bool = False,

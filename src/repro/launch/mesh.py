@@ -3,22 +3,13 @@ device state)."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def mesh_axis_types(n: int):
-    """``jax.sharding.AxisType`` appeared with explicit sharding in newer
-    jax; on older releases meshes are implicitly Auto. Returns the
-    ``axis_types`` kwarg value, or None when the installed jax predates it."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return (at.Auto,) * n if at is not None else None
-
-
-def make_mesh_compat(shape, axes):
-    """jax.make_mesh across jax versions with/without axis_types."""
-    at = mesh_axis_types(len(axes))
-    if at is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=at)
+def make_mesh(shape, axes):
+    """jax.make_mesh with every axis Auto: sharding follows the
+    ``constrain`` hints and jit shardings, not explicit-axis typing."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -26,9 +17,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: 2 pods x 256 = 512 chips (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Small mesh over whatever devices exist (tests)."""
-    return make_mesh_compat((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

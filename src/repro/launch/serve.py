@@ -20,6 +20,7 @@ from repro.core import optimizer as OPT
 from repro.core import profiler as PF
 from repro.core import trace as TR
 from repro.core.pipeline import PipelineModel
+from repro.launch.compile_cache import use_compile_cache
 from repro.serving.engine import PipelineEngine, StageServer
 
 # pipelines over the assigned architectures (analogues of the paper's five)
@@ -34,19 +35,30 @@ ENGINE_PIPELINES = {
 }
 
 
-def build_pipeline(name: str, *, gen_tokens: int = 4, profile_batches=(1, 2, 4),
-                   th: float = 2.0, verbose: bool = True):
-    """Returns (PipelineModel for the control plane, PipelineEngine)."""
+def pipeline_families(name: str):
+    """The (stage name, variant family) list of an ENGINE_PIPELINES entry."""
+    return [(arch, configs.get_variant_family(arch))
+            for arch, _ in ENGINE_PIPELINES[name]]
+
+
+def build_pipeline(name: str, families, *, gen_tokens: int = 4,
+                   profile_batches=(1, 2, 4), th: float = 2.0,
+                   verbose: bool = True):
+    """Profile one StageServer per stage and build the control-plane model.
+
+    ``families``: (stage name, variant family) per stage, in pipeline order;
+    a family is a list of (variant name, ModelConfig, accuracy).
+    Returns (PipelineModel for the control plane, PipelineEngine).
+    """
     servers = []
     stages = []
-    for arch, _ in ENGINE_PIPELINES[name]:
-        fam = configs.get_variant_family(arch)
-        srv = StageServer(arch, fam, gen_tokens=gen_tokens)
+    for stage_name, fam in families:
+        srv = StageServer(stage_name, fam, gen_tokens=gen_tokens)
         if verbose:
-            print(f"profiling stage {arch} ({len(fam)} variants)...",
+            print(f"profiling stage {stage_name} ({len(fam)} variants)...",
                   flush=True)
         profs = PF.profile_stage_server(srv, batches=profile_batches)
-        stage = PF.build_stage(arch, profs, th=th,
+        stage = PF.build_stage(stage_name, profs, th=th,
                                batch_choices=profile_batches,
                                max_batch=max(profile_batches))
         servers.append(srv)
@@ -68,7 +80,9 @@ def main() -> None:
                     help="scale the trace to this machine's capacity")
     args = ap.parse_args()
 
-    pipe, engine = build_pipeline(args.pipeline)
+    use_compile_cache()
+    pipe, engine = build_pipeline(args.pipeline,
+                                  pipeline_families(args.pipeline))
     print(f"pipeline SLA_P = {pipe.sla:.2f}s")
     rates = TR.excerpt(args.trace, seconds=args.seconds) * args.scale_rps
     obj = OPT.Objective(alpha=args.alpha, beta=args.beta, metric="pas")

@@ -9,8 +9,8 @@ implementations:
   * ``chunked`` -- lax.scan over query chunks with online softmax; O(S * C)
                    memory, the XLA analogue of flash attention (default for
                    long sequences and the dry-run path),
-  * ``pallas``  -- the Pallas TPU kernel from ``repro.kernels`` (validated in
-                   interpret mode on CPU; the target path on real TPUs).
+  * ``pallas``  -- the Pallas TPU kernel from ``repro.kernels`` (lowers to
+                   Mosaic, so TPU only; the tests run it in interpret mode).
 """
 from __future__ import annotations
 

@@ -91,9 +91,11 @@ def init_layer(rng, cfg: ModelConfig, spec: LayerSpec):
 def init_stack(rng, cfg: ModelConfig, pl: StackPlan):
     blocks = []
     for j, spec in enumerate(pl.pattern):
-        reps = [init_layer(jax.random.fold_in(rng, r * pl.period + j), cfg, spec)
-                for r in range(pl.n_rep)]
-        blocks.append(jax.tree.map(lambda *xs: jnp.stack(xs), *reps))
+        # vmapped over the repeats: each leaf is built stacked, never as a
+        # list of per-layer arrays plus a stacked copy
+        keys = jax.vmap(lambda r: jax.random.fold_in(rng, r * pl.period + j))(
+            jnp.arange(pl.n_rep))
+        blocks.append(jax.vmap(lambda k: init_layer(k, cfg, spec))(keys))
     rem = [init_layer(jax.random.fold_in(rng, pl.n_rep * pl.period + j), cfg, spec)
            for j, spec in enumerate(pl.rem)]
     return {"blocks": tuple(blocks), "rem": tuple(rem)}

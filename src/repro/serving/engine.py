@@ -21,6 +21,9 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.models import model as M
 
+# one program per config: the weights are written once, in their own dtype
+_init_params = jax.jit(M.init, static_argnums=1)
+
 
 class StageServer:
     def __init__(self, name: str,
@@ -37,7 +40,7 @@ class StageServer:
             if params_by_variant and vname in params_by_variant:
                 self.params[vname] = params_by_variant[vname]
             else:
-                self.params[vname] = M.init(jax.random.PRNGKey(seed + i), cfg)
+                self.params[vname] = _init_params(jax.random.PRNGKey(seed + i), cfg)
         self.active = list(self.variants)[0]
         self._prefill_cache = {}
         self._decode_cache = {}

@@ -99,8 +99,8 @@ from repro.training import optim
 from repro.training.train import make_train_step
 
 cfg = configs.get_config("qwen2-moe-a2.7b", reduced=True)
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 dapi.set_axis_rules(shd.axis_rules(mesh))
 params = M.init(jax.random.PRNGKey(0), cfg)
 opt = optim.init_state(params)
@@ -111,16 +111,7 @@ rng = jax.random.PRNGKey(1)
 batch = {"tokens": jax.random.randint(rng, (8, 32), 0, cfg.vocab)}
 batch["labels"] = batch["tokens"]
 bspec = {k: P("data", None) for k in batch}
-# newer jax: jax.set_mesh + PartitionSpec shardings; older jax: the Mesh is
-# the context manager and jit needs concrete NamedShardings
-mesh_ctx = getattr(jax, "set_mesh", None)
-if mesh_ctx is None:
-    mesh_ctx = lambda m: m
-    to_sh = lambda tree: jax.tree.map(
-        lambda sp: jax.sharding.NamedSharding(mesh, sp), tree,
-        is_leaf=lambda x: isinstance(x, P))
-    pspec, ospec, bspec = to_sh(pspec), to_sh(ospec), to_sh(bspec)
-with mesh_ctx(mesh):
+with jax.set_mesh(mesh):
     jitted = jax.jit(step, in_shardings=(pspec, ospec, bspec),
                      out_shardings=(pspec, ospec, None))
     p2, o2, m = jitted(params, opt, batch)

@@ -1,4 +1,6 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret mode."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -116,9 +118,11 @@ def test_ssd_scan_initial_state_continuation():
                                rtol=1e-3)
 
 
-def test_model_layer_pallas_path_matches_jnp():
+def test_model_layer_pallas_path_matches_jnp(monkeypatch):
     """attention(impl='pallas') inside the model layer == chunked/naive."""
     from repro.models import layers as L
+    monkeypatch.setattr(ops, "flash_attention",
+                        functools.partial(ops.flash_attention, interpret=True))
     ks = jax.random.split(jax.random.PRNGKey(6), 3)
     b, s, h, kv, hd = 2, 128, 4, 2, 64
     q = jax.random.normal(ks[0], (b, s, h, hd))
@@ -131,9 +135,11 @@ def test_model_layer_pallas_path_matches_jnp():
                                rtol=2e-4)
 
 
-def test_mamba_layer_pallas_path_matches_jnp():
+def test_mamba_layer_pallas_path_matches_jnp(monkeypatch):
     from repro.configs.base import SSMConfig
     from repro.models import ssm as S
+    monkeypatch.setattr(ops, "ssd_scan",
+                        functools.partial(ops.ssd_scan, interpret=True))
     scfg = SSMConfig(d_state=16, head_dim=32, expand=2, chunk_size=32)
     d = 64
     params = S.init_mamba(jax.random.PRNGKey(7), d, scfg, jnp.float32)

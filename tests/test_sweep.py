@@ -14,7 +14,10 @@
   comparison).  The legacy int-seed arithmetic is pinned bit-for-bit.
 * **resume**: missing/corrupt/stale shards are recomputed, matching ones
   are trusted, and a resumed run reproduces the fresh run's hash.
+* **no JAX in the workers**: the worker module imports no JAX, so a
+  worker never contends for a chip.
 """
+import importlib
 import json
 import os
 import pickle
@@ -38,6 +41,18 @@ def tiny_grid(reps: int = 2, seconds: int = 20):
     budgets = ST.resolve_budgets(2, (0.7,))
     return ST.build_grid(("ipa", "split_ipa"), (1.0,), budgets, reps,
                          (0.02,), seconds=seconds, n_pipelines=2)
+
+
+def test_worker_module_imports_no_jax(monkeypatch):
+    """Spawn workers import repro.core.study; it must not pull in JAX.
+
+    Imported afresh with every jax module blocked (a None entry in
+    sys.modules makes its import raise); teardown restores both."""
+    for name in [m for m in sys.modules if m.split(".")[0] == "repro"]:
+        monkeypatch.delitem(sys.modules, name)
+    for name in [m for m in sys.modules if m.split(".")[0] == "jax"]:
+        monkeypatch.setitem(sys.modules, name, None)
+    importlib.import_module("repro.core.study")
 
 
 # ---------------------------------------------------------------------------
