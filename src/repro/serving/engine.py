@@ -17,12 +17,20 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.models import model as M
 
 # one program per config: the weights are written once, in their own dtype
 _init_params = jax.jit(M.init, static_argnums=1)
+
+# Host spans inside ``StageServer.process``. They cost under a microsecond
+# each with no profiler running, and land in a profile on the device ops'
+# clock, so device idle can be put down to the host work around it.
+SPAN_PREFILL = "stage.prefill"   # prefill dispatch and its first argmax
+SPAN_DECODE = "stage.decode"     # one token step; its index as ``step``
+SPAN_FETCH = "stage.fetch"       # gather the tokens and copy them back
 
 
 class StageServer:
@@ -66,12 +74,12 @@ class StageServer:
             cap = min(self.max_ctx, s + self.gen_tokens)
 
             @jax.jit
-            def fn(params, tokens):
+            def prefill_step(params, tokens):
                 hl, caches, _ = M.prefill(params, cfg, {"tokens": tokens},
                                           impl="naive", capacity=cap)
                 lg = jnp.einsum("bd,vd->bv", hl, params["embed"])
                 return lg, caches
-            self._prefill_cache[key] = fn
+            self._prefill_cache[key] = prefill_step
         return self._prefill_cache[key]
 
     def _get_decode(self, vname: str, b: int):
@@ -80,9 +88,9 @@ class StageServer:
             cfg = self.variants[vname][0]
 
             @jax.jit
-            def fn(params, caches, clen, tok):
+            def decode_step(params, caches, clen, tok):
                 return M.decode_step(params, cfg, caches, clen, tok)
-            self._decode_cache[key] = fn
+            self._decode_cache[key] = decode_step
         return self._decode_cache[key]
 
     def process(self, tokens: np.ndarray) -> Tuple[np.ndarray, float]:
@@ -97,18 +105,22 @@ class StageServer:
         prefill = self._get_prefill(self.active, b, s)
         decode = self._get_decode(self.active, b)
         params = self.params[self.active]
-        lg, caches = prefill(params, jnp.asarray(tokens))
-        out = []
-        tok = jnp.argmax(lg, axis=-1).astype(jnp.int32)[:, None]
-        clen = s
-        for _ in range(self.gen_tokens):
-            out.append(tok)
-            lg, caches = decode(params, caches, jnp.int32(clen), tok)
+        with TraceAnnotation(SPAN_PREFILL):
+            lg, caches = prefill(params, jnp.asarray(tokens))
             tok = jnp.argmax(lg, axis=-1).astype(jnp.int32)[:, None]
+        out = []
+        clen = s
+        for i in range(self.gen_tokens):
+            with TraceAnnotation(SPAN_DECODE, step=i):
+                out.append(tok)
+                lg, caches = decode(params, caches, jnp.int32(clen), tok)
+                tok = jnp.argmax(lg, axis=-1).astype(jnp.int32)[:, None]
             clen += 1
-        gen = jnp.concatenate(out, axis=1)
-        gen.block_until_ready()
-        return np.asarray(gen), time.perf_counter() - t0
+        with TraceAnnotation(SPAN_FETCH):
+            gen = jnp.concatenate(out, axis=1)
+            gen.block_until_ready()
+            gen = np.asarray(gen)
+        return gen, time.perf_counter() - t0
 
 
 class PipelineEngine:

@@ -98,14 +98,3 @@ class RequestPool:
 
     def release_many(self, reqs) -> None:
         self._free.extend(reqs)
-
-
-@dataclasses.dataclass
-class BatchRecord:
-    stage: int
-    size: int
-    formed_at: float
-    started: float
-    finished: float
-    replica: int
-    variant: str
