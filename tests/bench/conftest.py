@@ -34,6 +34,7 @@ SSM = {"family": "ssm", "d_model": 128, "n_layer": 2, "vocab_size": 500,
 # (bf16 rounding of a one- or two-layer model), and the fp8 control at
 # least 0.097 (each seed's worse stage); the faults tested read far more.
 LIMIT = 0.05
+SEED = 2 ** 31 + 11
 
 
 def tiny_chain(rate=40.0, sla=(0.6, 0.4), interval=0.5, batches=(2,)):
@@ -65,7 +66,7 @@ def tiny_cell():
     short checked window)."""
     from bench import check as CK
     from bench.driver import Cell
-    cell = Cell(tiny_chain(), seed=2 ** 31 + 11, log=lambda msg: None)
+    cell = Cell(tiny_chain(), seed=SEED, log=lambda msg: None)
     cell.setup()
     CK.check(cell, cell.run_window(0.3), controls=("fp8",))
     return cell

@@ -1,13 +1,25 @@
 """The driver loop in-process on the CPU, on a tiny two-stage chain: requests
 flow through both stages and across planner boundaries, and the check
-passes a sound run and fails each fault a cell can have."""
+passes a sound run and fails each fault a cell can have.
+
+The faults in the served tokens are planted in the model function
+``repro.models.model.decode_step``, which every way of driving the token
+loop calls, and not in how ``StageServer.process`` drives the device: the
+check judges what ``process`` returns."""
+import time
+
+import jax
+import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from bench import check as CK
 from bench import generator as GEN
+from repro.models import model as M
+from repro.serving import engine
 from repro.serving.engine import StageServer
 
-from conftest import LIMIT
+from conftest import LIMIT, SEED, tiny_chain
 
 
 def test_requests_flow_through_both_stages_and_boundaries(tiny_cell):
@@ -65,7 +77,6 @@ def _run(cell, monkeypatch):
     """The rest of a benchmark run on the already set-up cell: window, peak
     memory, check, metrics and the result object, the look for a chip
     skipped (the CPU's device kind has no peaks, so the test gives some)."""
-    import jax
     from bench import run as R
     monkeypatch.setattr(R, "peaks_for", lambda kind: PEAKS)
     args = R.parse(["--workload", cell.spec.name, "--seed", str(cell.seed),
@@ -90,14 +101,47 @@ def test_run_result_of_a_sound_run(tiny_cell, monkeypatch):
                for n in result["compared"].values())
 
 
-def test_fault_state_unchanged_fails(tiny_cell, monkeypatch):
+def _state_unchanged(step, spec):
+    """The real step's logits, with the caches it was given."""
+    def broken(params, cfg, caches, cache_len, tokens, **kw):
+        return step(params, cfg, caches, cache_len, tokens, **kw)[0], caches
+    return broken
+
+
+def _token_altered(step, spec):
+    """Token 0 wins the step whose context is the stage's prompt + 1: the
+    third generated token of every row. The condition is on the traced
+    length, so it holds inside any loop."""
+    at = {st.name: st.prompt_tokens + 1 for st in spec.stages}
+
+    def broken(params, cfg, caches, cache_len, tokens, **kw):
+        lg, caches = step(params, cfg, caches, cache_len, tokens, **kw)
+        return jnp.where(cache_len == at[cfg.arch_id],
+                         lg.at[:, 0].set(1e4), lg), caches
+    return broken
+
+
+def _cell_with(monkeypatch, fault=None, server=None):
+    """A fresh tiny cell whose programs trace ``fault`` wrapped round the
+    program's ``decode_step``, served by ``server`` in place of
+    ``StageServer`` where given. The patches come before the cell is built
+    and set up, so profiling, warm-up and the window all run them; the
+    reference imports nothing of ``repro.models``."""
+    from bench.driver import Cell
+    spec = tiny_chain()
+    if fault is not None:
+        monkeypatch.setattr(M, "decode_step", fault(M.decode_step, spec))
+    if server is not None:
+        monkeypatch.setattr(engine, "StageServer", server)
+    cell = Cell(spec, seed=SEED, log=lambda msg: None)
+    cell.setup()
+    return cell
+
+
+def test_fault_state_unchanged_fails(monkeypatch):
     """Each decode step returns the cache it was given."""
-    for srv in tiny_cell.servers:
-        for key, fn in list(srv._decode_cache.items()):
-            monkeypatch.setitem(
-                srv._decode_cache, key,
-                lambda p, c, n, t, f=fn: (f(p, c, n, t)[0], c))
-    result = _broken_run(tiny_cell, monkeypatch)
+    cell = _cell_with(monkeypatch, _state_unchanged)
+    result = _broken_run(cell, monkeypatch)
     assert result["correct"] is False
     assert max(n["value"] for n in result["compared"].values()) > LIMIT
 
@@ -118,18 +162,81 @@ def test_fault_half_batch_left_out_fails(tiny_cell, monkeypatch):
     assert result["correct"] is False
 
 
-def test_fault_token_altered_where_produced_fails(tiny_cell, monkeypatch):
+def test_fault_token_altered_where_produced_fails(monkeypatch):
     """The third generated token of every row is forced to token 0."""
-    for srv in tiny_cell.servers:
-        prompt = next(iter(srv._prefill_cache))[2]
-        for key, fn in list(srv._decode_cache.items()):
-            def altered(p, c, n, t, f=fn, at=prompt + 1):
-                lg, c = f(p, c, n, t)
-                return (lg.at[:, 0].set(1e4) if int(n) == at else lg), c
-            monkeypatch.setitem(srv._decode_cache, key, altered)
-    result = _broken_run(tiny_cell, monkeypatch)
+    cell = _cell_with(monkeypatch, _token_altered)
+    result = _broken_run(cell, monkeypatch)
     assert result["correct"] is False
     assert max(n["value"] for n in result["compared"].values()) > LIMIT
+
+
+class _DeviceLoopServer(StageServer):
+    """A stage server whose token loop runs on the device: the prefill
+    program, then one program of ``gen_tokens - 1`` decode steps with the
+    argmax inside. A stand-in for such a program, to show that the faults
+    above do not depend on how ``process`` drives the device."""
+
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
+        self.programs = {}
+
+    def _compiled(self, vname: str, s: int):
+        if (vname, s) not in self.programs:
+            cfg = self.variants[vname][0]
+            cap = min(self.max_ctx, s + self.gen_tokens)
+            n_steps = self.gen_tokens - 1
+
+            @jax.jit
+            def prefill(params, tokens):
+                hl, caches, _ = M.prefill(params, cfg, {"tokens": tokens},
+                                          impl="naive", capacity=cap)
+                lg = jnp.einsum("bd,vd->bv", hl, params["embed"])
+                tok = jnp.argmax(lg, axis=-1).astype(jnp.int32)[:, None]
+                return tok, caches
+
+            @jax.jit
+            def generate(params, caches, tok):
+                def step(carry, clen):
+                    caches, tok = carry
+                    lg, caches = M.decode_step(params, cfg, caches, clen, tok)
+                    tok = jnp.argmax(lg, axis=-1).astype(jnp.int32)[:, None]
+                    return (caches, tok), tok[:, 0]
+                clens = s + jnp.arange(n_steps, dtype=jnp.int32)
+                _, toks = jax.lax.scan(step, (caches, tok), clens)
+                return jnp.concatenate([tok, toks.T], axis=1)
+            self.programs[vname, s] = prefill, generate
+        return self.programs[vname, s]
+
+    def process(self, tokens):
+        tokens = np.asarray(tokens, np.int32) % self.config.vocab
+        t0 = time.perf_counter()
+        prefill, generate = self._compiled(self.active, tokens.shape[1])
+        params = self.params[self.active]
+        tok, caches = prefill(params, jnp.asarray(tokens))
+        gen = np.asarray(generate(params, caches, tok))
+        return gen, time.perf_counter() - t0
+
+
+FAULTS = {"sound": None, "state_unchanged": _state_unchanged,
+          "token_altered": _token_altered}
+
+
+@pytest.mark.parametrize("case", list(FAULTS))
+def test_verdicts_hold_for_a_device_token_loop(case, monkeypatch):
+    """With the token loop on the device, a sound run is correct with
+    nothing compiled in the window, and each fault planted in
+    ``decode_step`` fails the check."""
+    cell = _cell_with(monkeypatch, FAULTS[case], _DeviceLoopServer)
+    assert all(isinstance(srv, _DeviceLoopServer) for srv in cell.servers)
+    rec = cell.run_window(0.6)
+    verdict = CK.check(cell, rec)
+    gap = max(n["value"] for n in verdict["numbers"])
+    if case == "sound":
+        assert verdict["correct"] is True and rec["compiles_in_window"] == 0
+        assert all(r.done is not None for r in rec["requests"])
+        assert gap <= LIMIT
+    else:
+        assert verdict["correct"] is False and gap > LIMIT
 
 
 def test_calibrate_reads_limits_and_knee_through_run_set_up(
@@ -140,7 +247,6 @@ def test_calibrate_reads_limits_and_knee_through_run_set_up(
     import argparse
     import json
 
-    import jax
     from bench import calibrate as CA
     from bench import run as R
     monkeypatch.setattr(R, "set_up",
