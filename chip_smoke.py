@@ -14,7 +14,8 @@ no CPU path.  Each phase prints one JSON line; any failure ends the run.
                one variant switch.
   cache_check  the served variant's logits from prefill plus decode steps
                through the KV cache, against ``model.forward`` over the
-               whole sequence with the same tokens and weights.
+               whole sequence with the same weights and tokens: the
+               prompt and the decode steps' own greedy picks.
   kernels      flash_attention, decode_attention and ssd_scan lowered to
                Mosaic (``interpret=False``), against ``repro.kernels.ref``.
 
@@ -172,20 +173,23 @@ def phase_cache_check(engine):
     variant = stage_family()[0][0]      # the 30-layer published config
     cfg = srv.variants[variant][0]
     params = srv.params[variant]
-    seq = np.random.default_rng(SEED + 1).integers(
-        0, cfg.vocab, (BATCH, PROMPT_LEN + DECODE_STEPS), dtype=np.int32)
+    prompt = np.random.default_rng(SEED + 1).integers(
+        0, cfg.vocab, (BATCH, PROMPT_LEN), dtype=np.int32)
 
-    # the server's own compiled prefill and decode programs
+    # the server's own compiled prefill and decode programs; each decode
+    # step picks its token from the logits it is given, and takes them
+    # (donated), so they are copied back first
     prefill = srv._get_prefill(variant, BATCH, PROMPT_LEN)
     decode = srv._get_decode(variant, BATCH)
-    lg, caches = prefill(params, jnp.asarray(seq[:, :PROMPT_LEN]))
-    cached = [lg]                       # position PROMPT_LEN - 1
-    for t in range(DECODE_STEPS):       # positions PROMPT_LEN .. + t
-        pos = PROMPT_LEN + t
-        lg, caches = decode(params, caches, jnp.int32(pos),
-                            jnp.asarray(seq[:, pos:pos + 1]))
-        cached.append(lg)
-    cached = np.asarray(jnp.stack(cached, axis=1), np.float32)
+    lg, caches, clen, gen = prefill(params, jnp.asarray(prompt))
+    cached = []                         # positions PROMPT_LEN - 1 .. + t
+    for t in range(DECODE_STEPS + 1):
+        cached.append(np.asarray(lg, np.float32))
+        if t < DECODE_STEPS:
+            lg, caches, clen, gen = decode(params, lg, caches, clen, gen)
+    cached = np.stack(cached, axis=1)
+    picked = cached[:, :DECODE_STEPS].argmax(-1).astype(np.int32)
+    seq = np.concatenate([prompt, picked], axis=1)
 
     @jax.jit
     def full_logits(params, tokens):

@@ -28,9 +28,22 @@ _init_params = jax.jit(M.init, static_argnums=1)
 # Host spans inside ``StageServer.process``. They cost under a microsecond
 # each with no profiler running, and land in a profile on the device ops'
 # clock, so device idle can be put down to the host work around it.
-SPAN_PREFILL = "stage.prefill"   # prefill dispatch and its first argmax
-SPAN_DECODE = "stage.decode"     # one token step; its index as ``step``
-SPAN_FETCH = "stage.fetch"       # gather the tokens and copy them back
+SPAN_PREFILL = "stage.prefill"   # prefill dispatch
+SPAN_DECODE = "stage.decode"     # pick, keep and advance token ``step``
+SPAN_FETCH = "stage.fetch"       # wait for the kept tokens, copy them back
+
+
+def _keep(lg, gen):
+    """The greedy pick from ``lg`` (B, V), shifted into the last column of
+    ``gen`` (B, n): after n picks, column i holds token i."""
+    tok = jnp.argmax(lg, axis=-1).astype(jnp.int32)[:, None]
+    return tok, jnp.concatenate([gen[:, 1:], tok], axis=1)
+
+
+@functools.partial(jax.jit, donate_argnums=1)
+def keep_last(lg, gen):
+    """Pick and keep the last token; no decode step follows it."""
+    return _keep(lg, gen)[1]
 
 
 class StageServer:
@@ -68,33 +81,42 @@ class StageServer:
 
     # -- data plane -----------------------------------------------------------
     def _get_prefill(self, vname: str, b: int, s: int):
+        """(params, tokens (B, S)) -> (logits of token 0, caches, cache
+        length S, an empty (B, gen_tokens) buffer), all on the device."""
         key = (vname, b, s)
         if key not in self._prefill_cache:
             cfg = self.variants[vname][0]
             cap = min(self.max_ctx, s + self.gen_tokens)
+            empty = (b, self.gen_tokens)
 
             @jax.jit
             def prefill_step(params, tokens):
                 hl, caches, _ = M.prefill(params, cfg, {"tokens": tokens},
                                           impl="naive", capacity=cap)
                 lg = jnp.einsum("bd,vd->bv", hl, params["embed"])
-                return lg, caches
+                return lg, caches, jnp.int32(s), jnp.zeros(empty, jnp.int32)
             self._prefill_cache[key] = prefill_step
         return self._prefill_cache[key]
 
     def _get_decode(self, vname: str, b: int):
+        """(params, logits of token i, caches, cache length, buffer) ->
+        the same for token i + 1: token i picked, kept and decoded."""
         key = (vname, b)
         if key not in self._decode_cache:
             cfg = self.variants[vname][0]
 
-            @jax.jit
-            def decode_step(params, caches, clen, tok):
-                return M.decode_step(params, cfg, caches, clen, tok)
+            @functools.partial(jax.jit, donate_argnums=(1, 2, 4))
+            def decode_step(params, lg, caches, clen, gen):
+                tok, gen = _keep(lg, gen)
+                lg, caches = M.decode_step(params, cfg, caches, clen, tok)
+                return lg, caches, clen + 1, gen
             self._decode_cache[key] = decode_step
         return self._decode_cache[key]
 
     def process(self, tokens: np.ndarray) -> Tuple[np.ndarray, float]:
-        """tokens: (B, S) int32 prompts. Greedy-decodes ``gen_tokens``.
+        """tokens: (B, S) int32 prompts. Greedy-decodes ``gen_tokens``: the
+        first from prefill's logits, each later one from a decode step, so
+        ``gen_tokens - 1`` steps run, one program each.
 
         Returns (generated (B, gen_tokens), wall_seconds).
         """
@@ -106,18 +128,14 @@ class StageServer:
         decode = self._get_decode(self.active, b)
         params = self.params[self.active]
         with TraceAnnotation(SPAN_PREFILL):
-            lg, caches = prefill(params, jnp.asarray(tokens))
-            tok = jnp.argmax(lg, axis=-1).astype(jnp.int32)[:, None]
-        out = []
-        clen = s
-        for i in range(self.gen_tokens):
+            lg, caches, clen, gen = prefill(params, jnp.asarray(tokens))
+        last = self.gen_tokens - 1
+        for i in range(last):
             with TraceAnnotation(SPAN_DECODE, step=i):
-                out.append(tok)
-                lg, caches = decode(params, caches, jnp.int32(clen), tok)
-                tok = jnp.argmax(lg, axis=-1).astype(jnp.int32)[:, None]
-            clen += 1
+                lg, caches, clen, gen = decode(params, lg, caches, clen, gen)
+        with TraceAnnotation(SPAN_DECODE, step=last):
+            gen = keep_last(lg, gen)
         with TraceAnnotation(SPAN_FETCH):
-            gen = jnp.concatenate(out, axis=1)
             gen.block_until_ready()
             gen = np.asarray(gen)
         return gen, time.perf_counter() - t0
